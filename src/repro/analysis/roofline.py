@@ -131,10 +131,7 @@ def roofline_from_compiled(
     """The three roofline terms + raw counters for one compiled step."""
     cost = {}
     try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, list):  # older jax returns [dict]
-            ca = ca[0]
-        cost = dict(ca) if ca else {}
+        cost = dict(compiled.cost_analysis() or {})
     except Exception:
         pass
     flops = float(cost.get("flops", 0.0))

@@ -44,6 +44,9 @@ def _warn_if_interpret_cpu(path: str) -> None:
 
 
 def main(argv=None) -> int:
+    from repro.common import env
+
+    env.use_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.bench",
         description="unified estimator x precision x shape benchmark",
@@ -80,8 +83,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.platform:
-        from repro.common import env
-
         env.set_platform(args.platform)
 
     from repro.bench import schema

@@ -11,6 +11,7 @@ from __future__ import annotations
 import os
 import warnings
 from multiprocessing import cpu_count
+from pathlib import Path
 
 import jax
 
@@ -21,7 +22,26 @@ __all__ = [
     "set_debug_nan",
     "add_xla_flags",
     "platform_provenance",
+    "use_compile_cache",
 ]
+
+# <checkout>/.xla-cache: a fixed path (the cache key includes it), listed
+# in .gitignore, and the directory CI restores.
+_CHECKOUT_XLA_CACHE = Path(__file__).resolve().parents[3] / ".xla-cache"
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; return its directory.
+
+    Where ``$JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set here. Otherwise the cache goes to
+    ``<checkout>/.xla-cache``. Entry points call this first thing.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_XLA_CACHE))
+    return str(_CHECKOUT_XLA_CACHE)
 
 
 def platform_provenance() -> dict:

@@ -188,7 +188,9 @@ class GrowableFeatureMap:
         """
         est = registry.get(self.estimator)
         if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
+            from repro.kernels.common import default_interpret
+
+            use_pallas = not default_interpret()
         zs = [
             est.apply(self.plan,
                       jax.tree_util.tree_map(lambda a: a[g], self.params),
@@ -220,7 +222,9 @@ class GrowableFeatureMap:
         the sharded psum reduction)."""
         est = registry.get(self.estimator)
         if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
+            from repro.kernels.common import default_interpret
+
+            use_pallas = not default_interpret()
         inv_g = 1.0 / self.n_generations
 
         def _apply_fn(g):

@@ -45,6 +45,7 @@ from repro.distributed.sharding import (
     estimator_param_specs,
     shard_map,
 )
+from repro.kernels.common import default_interpret
 
 __all__ = [
     "FEATURE_AXIS",
@@ -155,7 +156,7 @@ def sharded_apply(
     """
     est = registry.get(name)
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = not default_interpret()
     if mesh is None:
         return _reference_apply(est, plan, params, x,
                                 accum_dtype=accum_dtype,
@@ -207,7 +208,7 @@ def sharded_estimate_gram(
     """
     est = registry.get(name)
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = not default_interpret()
     s = _num_shards(params)
     inv_s = 1.0 / s
 

@@ -30,26 +30,14 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # newer jax: public jax.shard_map with check_vma
-    _jax_shard_map = jax.shard_map
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with varying-manual-axes checks off.
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-except AttributeError:  # pinned jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
-
-shard_map.__doc__ = """Version-portable ``shard_map`` (replication checks off).
-
-Every shard_map in the repo (MoE expert parallelism, compressed psum, the
-sharded estimator path) goes through this wrapper so the jax-pin difference
-(``jax.shard_map``/``check_vma`` vs ``jax.experimental.shard_map``/
-``check_rep``) lives in exactly one place."""
+    Every shard_map in the repo (MoE expert parallelism, compressed psum,
+    the sharded estimator path) goes through this wrapper: their bodies
+    mix replicated and per-shard values the VMA checker cannot type."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 # THE feature axis: random-feature columns (and the stacked per-shard
 # estimator params backing them) shard over this name — used as both the

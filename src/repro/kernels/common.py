@@ -139,7 +139,9 @@ def pick_batch_block(
 # persistent per-(kernel, shape, dtype, backend) block cache
 # ---------------------------------------------------------------------------
 _CACHE_ENV = "REPRO_BLOCK_CACHE"
-_DEFAULT_CACHE = "~/.cache/repro/feature_blocks.json"
+# Inside the checkout, so only a committed file can steer which tiles the
+# main path compiles; absent -> the VMEM heuristic.
+_DEFAULT_CACHE = Path(__file__).resolve().parents[3] / "feature_blocks.json"
 
 _block_cache: Optional[Dict[str, list]] = None
 _block_cache_path: Optional[Path] = None

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.ctr.ref import ctr_feature_fused_ref
-from repro.kernels.common import default_interpret as _default_interpret
+from repro.kernels import common as _kcommon
 from repro.kernels.common import get_feature_blocks as _get_blocks
 from repro.kernels.common import round_up as _round_up
 from repro.obs.trace import kernel_scope as _kernel_scope
@@ -45,7 +45,7 @@ def ctr_feature_fused(
     under the mixed precision policy); both accumulators are fp32.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = _kcommon.default_interpret()
     batch_shape = x.shape[:-1]
     d = x.shape[-1]
     k, fc, _ = wr.shape

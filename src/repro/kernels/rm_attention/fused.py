@@ -58,7 +58,7 @@ def _featurize_block(x, w_ref, deg, scale):
     bf = deg.shape[-1]
 
     def step(j, acc):
-        w = pl.load(w_ref, (pl.ds(j, 1), slice(None), slice(None)))
+        w = w_ref[pl.ds(j, 1), :, :]
         w = w.reshape(w.shape[1], w.shape[2])          # [bf, d]
         pj = jax.lax.dot_general(
             x, w,
@@ -114,8 +114,8 @@ def _fused_causal_kernel(q_ref, k_ref, v_ref, kval_ref, w_ref, deg_ref,
 
     # cross-chunk contribution reads the state BEFORE chunk i is folded in
     # (the state scratch holds chunks < i for this feature block).
-    s_j = pl.load(s_scr, (pl.ds(j, 1), slice(None), slice(None)))[0]
-    n_j = pl.load(n_scr, (pl.ds(j, 1), slice(None)))           # [1, bf]
+    s_j = s_scr[pl.ds(j, 1), :, :][0]
+    n_j = n_scr[pl.ds(j, 1), :]                        # [1, bf]
     num_scr[...] += jax.lax.dot_general(
         zq, s_j, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -128,8 +128,8 @@ def _fused_causal_kernel(q_ref, k_ref, v_ref, kval_ref, w_ref, deg_ref,
         zk, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                                  # [bf, dv]
     n_new = n_j + jnp.sum(zk, axis=0, keepdims=True)   # [1, bf]
-    pl.store(s_scr, (pl.ds(j, 1), slice(None), slice(None)), s_new[None])
-    pl.store(n_scr, (pl.ds(j, 1), slice(None)), n_new)
+    s_scr[pl.ds(j, 1), :, :] = s_new[None]
+    n_scr[pl.ds(j, 1), :] = n_new
 
     # last feature block: mask, combine intra-chunk and carried terms, emit.
     @pl.when(j == nfb - 1)

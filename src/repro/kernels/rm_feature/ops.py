@@ -23,7 +23,7 @@ from repro.kernels.rm_feature.rm_feature import (
     rm_feature_fused_pallas,
 )
 
-from repro.kernels.common import default_interpret as _default_interpret
+from repro.kernels import common as _kcommon
 from repro.kernels.common import get_feature_blocks as _get_blocks
 from repro.kernels.common import round_up as _round_up
 from repro.obs.trace import kernel_scope as _kernel_scope
@@ -61,7 +61,7 @@ def rm_feature_fused(
     output is fp32.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = _kcommon.default_interpret()
     batch_shape = x.shape[:-1]
     d = x.shape[-1]
     k, f, _ = w.shape
@@ -128,7 +128,7 @@ def rm_feature_bucket(
 ) -> jax.Array:
     """Apply one degree bucket: x [.., d], omega [count*degree, d] -> [.., count]."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = _kcommon.default_interpret()
     batch_shape = x.shape[:-1]
     d = x.shape[-1]
     count = omega.shape[0] // degree

@@ -45,7 +45,7 @@ def _rm_fused_kernel(x_ref, w_ref, deg_ref, scale_ref, o_ref):
     bf = deg.shape[-1]
 
     def step(j, acc):
-        w = pl.load(w_ref, (pl.ds(j, 1), slice(None), slice(None)))
+        w = w_ref[pl.ds(j, 1), :, :]
         w = w.reshape(w.shape[1], w.shape[2])     # [bf, d]
         pj = jax.lax.dot_general(
             x, w,

@@ -16,7 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret as _default_interpret
+from repro.kernels import common as _kcommon
 from repro.kernels.common import get_feature_blocks as _get_blocks
 from repro.kernels.common import round_up as _round_up
 from repro.kernels.structured_feature.structured_feature import (
@@ -50,7 +50,7 @@ def structured_feature_fused(
     under the mixed precision policy); the accumulator is fp32.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = _kcommon.default_interpret()
     batch_shape = x.shape[:-1]
     m = x.shape[-1]
     k, s, _ = d1.shape
@@ -68,10 +68,14 @@ def structured_feature_fused(
     bm, bf = blocks or _get_blocks("structured_feature", m, k, b, cols,
                                    dtype=x.dtype, weight_tensors=2,
                                    accumulators=4)
-    # feature tiles must cover WHOLE stacks: snap the ladder width down to
-    # a multiple of d_pad (never below one stack)
-    bf = max(m, bf - bf % m)
-    bs = bf // m
+    # feature tiles cover WHOLE stacks (the ladder width snapped down to a
+    # multiple of d_pad, never below one stack), and on TPU a tile's
+    # bs * d_pad lanes must be a multiple of 128 unless it spans every
+    # stack: round the stack count up to that unit, or take all stacks.
+    bs = max(1, bf // m)
+    unit = max(1, 128 // m)
+    bs = s if _round_up(bs, unit) >= s else _round_up(bs, unit)
+    bf = bs * m
     with _kernel_scope("structured_feature", x=x,
                        cost=dict(batch=b, d=m, depth=k, f=cols,
                                  itemsize=jnp.dtype(x.dtype).itemsize),

@@ -6,19 +6,20 @@ over degree slots — the ``rm_feature_fused`` loop — where slot j's
 projection is not an MXU matmul against drawn rows but the in-VMEM
 butterfly Walsh-Hadamard transform of the diagonally-signed input,
 
-    P_j = reshape( d2_j ∘ WHT( d1_j ∘ x ) ),
+    P_j = d2_j ∘ WHT( d1_j ∘ x ),
 
 computed per (batch, stack) tile in O(d_pad log d_pad) adds on the VPU —
 the sublinear-time structure of Choromanski & Sindhwani (2016). The
 butterfly matches the SYLVESTER Hadamard order exactly (the dense-matmul
 oracle in ``repro.structured.ref`` is the ground truth), unrolling
-log2(d_pad) reshape+concat stages at trace time.
+log2(d_pad) lane-roll stages at trace time.
 
 The grid tiles (batch, stack): each feature tile covers ``block_s`` whole
-stacks of ``d_pad`` columns, so the signed transforms broadcast cleanly and
-the per-column degree/scale metadata stays a flat ``[1, block_s * d_pad]``
-row. Columns are laid out in ascending degree order, so each tile's loop
-exits at the TILE's max depth, not the global one. The accumulator is an
+stacks of ``d_pad`` columns laid side by side on the lane axis — the sign
+tensors enter as ``[max_degree, S * d_pad]`` rows, like the per-column
+degree/scale metadata — so every operand stays 2-D. Columns are laid out
+in ascending degree order, so each tile's loop exits at the TILE's max
+depth, not the global one. The accumulator is an
 fp32 VMEM buffer; bf16 inputs are widened once on load (bf16-in /
 fp32-accum, same policy as the other feature kernels).
 """
@@ -29,47 +30,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _wht(v: jax.Array) -> jax.Array:
-    """Butterfly Walsh-Hadamard transform along the last axis (length a
-    power of two, static): Sylvester order, unnormalized (+-1 entries).
-    Unrolls at trace time — log2(m) reshape/concat stages."""
-    bm, bs, m = v.shape
+def _wht(v: jax.Array, m: int) -> jax.Array:
+    """Butterfly Walsh-Hadamard transform of every length-``m`` lane segment
+    of ``v [rows, n]`` (``m`` a static power of two dividing ``n``):
+    Sylvester order, unnormalized (+-1 entries).
+
+    Stage ``h`` pairs lane ``i`` with ``i ^ h`` — always inside ``i``'s own
+    segment — and maps ``(a, b) -> (a + b, a - b)``. The partner comes from
+    lane rolls, so the transform stays in the native (sublane, lane) layout
+    (no reshape of the lane dim, which the TPU lowering refuses). Which of
+    the two rolls holds lane ``i ^ h`` is read off a rolled iota, so the
+    result does not depend on the roll direction convention.
+    """
+    n = v.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
     h = 1
     while h < m:
-        v = v.reshape(bm, bs, m // (2 * h), 2, h)
-        a = v[:, :, :, 0, :]
-        b = v[:, :, :, 1, :]
-        v = jnp.concatenate([a + b, a - b], axis=-1).reshape(bm, bs, m)
+        fwd = pltpu.roll(v, h, 1)
+        bwd = pltpu.roll(v, n - h, 1)
+        from_fwd = pltpu.roll(lane, h, 1) == (lane ^ h)
+        partner = jnp.where(from_fwd, fwd, bwd)
+        v = jnp.where((lane & h) == 0, v + partner, partner - v)
         h *= 2
     return v
 
 
 def _structured_fused_kernel(x_ref, d1_ref, d2_ref, deg_ref, scale_ref,
-                             o_ref):
+                             o_ref, *, m: int):
     # Widen once on load: the WHT is pure adds/subs, so fp32 intermediates
     # keep the running product exactly fp32-accumulated under bf16 inputs.
     x = x_ref[...].astype(jnp.float32)            # [bm, m]
     deg = deg_ref[...]                            # [1, bs * m] int32
-    k, bs, m = d1_ref.shape
-    bm = x.shape[0]
+    bs = deg.shape[-1] // m
+    # every stack of the tile transforms the same input rows
+    xs = x if bs == 1 else jnp.concatenate([x] * bs, axis=-1)
 
     def step(j, acc):
-        d1 = pl.load(d1_ref, (pl.ds(j, 1), slice(None), slice(None)))
-        d1 = d1.reshape(bs, m).astype(jnp.float32)
-        d2 = pl.load(d2_ref, (pl.ds(j, 1), slice(None), slice(None)))
-        d2 = d2.reshape(bs, m).astype(jnp.float32)
-        u = x[:, None, :] * d1[None]              # [bm, bs, m]
-        v = _wht(u) * d2[None]
-        p = v.reshape(bm, bs * m)
-        keep = j < deg
-        return jnp.where(keep, acc * p, acc)
+        d1 = d1_ref[pl.ds(j, 1), :].astype(jnp.float32)   # [1, bs * m]
+        d2 = d2_ref[pl.ds(j, 1), :].astype(jnp.float32)
+        p = _wht(xs * d1, m) * d2
+        return jnp.where(j < deg, acc * p, acc)
 
     depth = jnp.max(deg)                          # tile-local product depth
-    acc = jax.lax.fori_loop(
-        0, depth, step, jnp.ones((bm, bs * m), jnp.float32)
-    )
+    acc = jax.lax.fori_loop(0, depth, step, jnp.ones(xs.shape, jnp.float32))
     scale = scale_ref[...].astype(jnp.float32)
     o_ref[...] = (acc * scale).astype(o_ref.dtype)
 
@@ -99,17 +105,20 @@ def structured_feature_fused_pallas(
     k, s, _ = d1.shape
     assert b % block_b == 0 and s % block_s == 0, (b, s, block_b, block_s)
     grid = (b // block_b, s // block_s)
+    bf = block_s * m
+    # stacks laid out along the lane axis: [max_degree, S * d_pad]
     return pl.pallas_call(
-        _structured_fused_kernel,
+        functools.partial(_structured_fused_kernel, m=m),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((k, block_s, m), lambda i, j: (0, j, 0)),
-            pl.BlockSpec((k, block_s, m), lambda i, j: (0, j, 0)),
-            pl.BlockSpec((1, block_s * m), lambda i, j: (0, j)),
-            pl.BlockSpec((1, block_s * m), lambda i, j: (0, j)),
+            pl.BlockSpec((k, bf), lambda i, j: (0, j)),
+            pl.BlockSpec((k, bf), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bf), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bf), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_b, block_s * m), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_b, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, s * m), jnp.float32),
         interpret=interpret,
-    )(x, d1, d2, col_deg.reshape(1, s * m), col_scale.reshape(1, s * m))
+    )(x, d1.reshape(k, s * m), d2.reshape(k, s * m),
+      col_deg.reshape(1, s * m), col_scale.reshape(1, s * m))

@@ -14,7 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret as _default_interpret
+from repro.kernels import common as _kcommon
 from repro.kernels.common import get_batch_block as _get_batch_block
 from repro.kernels.common import round_up as _round_up
 from repro.obs.trace import kernel_scope as _kernel_scope
@@ -48,7 +48,7 @@ def tensor_sketch_fused(
     is upcast to fp32 inside the kernel); accumulation is always fp32.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = _kcommon.default_interpret()
     batch_shape = x.shape[:-1]
     d = x.shape[-1]
     k, fs, _ = wr.shape

@@ -43,9 +43,9 @@ def _ts_fused_kernel(x_ref, wr_ref, wi_ref, deg_ref, mr_ref, mi_ref,
 
     def step(j, carry):
         ar, ai = carry
-        wr = pl.load(wr_ref, (pl.ds(j, 1), slice(None), slice(None)))
+        wr = wr_ref[pl.ds(j, 1), :, :]
         wr = wr.reshape(wr.shape[1], wr.shape[2])
-        wi = pl.load(wi_ref, (pl.ds(j, 1), slice(None), slice(None)))
+        wi = wi_ref[pl.ds(j, 1), :, :]
         wi = wi.reshape(wi.shape[1], wi.shape[2])
         dims = (((1,), (1,)), ((), ()))
         pr = jax.lax.dot_general(x, wr, dimension_numbers=dims,

@@ -5,48 +5,35 @@ importing this module never touches jax device state — required because the
 dry-run forces 512 host devices via XLA_FLAGS before any jax import, while
 tests/benches must keep seeing 1 device.
 
-Pin compatibility: ``jax.sharding.AxisType`` (explicit/auto axis types) only
-exists on newer jax releases. On pins without it every mesh axis is plain
-(implicitly Auto), which is exactly what ``shard_map``/``pjit`` expect here —
-so the kwarg is dropped rather than emulated.
+Every mesh in the repo is built here. Bare ``jax.make_mesh`` makes Explicit
+axes, which ``with_sharding_constraint`` and the name-rule table in
+``repro.distributed.sharding`` do not expect, so every axis is Auto.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
 import jax
-
-try:  # jax >= 0.5-era explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older pins: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def _auto(n: int) -> dict:
-    """axis_types kwargs for ``jax.make_mesh`` (empty on pins without them)."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n}
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """Auto-axis mesh of ``shape`` over the local devices."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 two-pod (512 chips) mesh."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto(len(axes)))
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh for tests/elastic reconfiguration."""
-    return jax.make_mesh(shape, axes, **_auto(len(axes)))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Mesh over whatever devices exist locally (CPU tests: 1..8 devices)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"), **_auto(2))
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 def make_feature_mesh(num_shards: Optional[int] = None):
@@ -61,4 +48,4 @@ def make_feature_mesh(num_shards: Optional[int] = None):
     from repro.distributed.sharding import FEATURE_AXIS
 
     n = len(jax.devices()) if num_shards is None else num_shards
-    return jax.make_mesh((n,), (FEATURE_AXIS,), **_auto(1))
+    return make_mesh((n,), (FEATURE_AXIS,))

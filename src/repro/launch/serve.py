@@ -104,6 +104,9 @@ def parse_tiers(spec: str):
 
 
 def main(argv=None):
+    from repro.common import env
+
+    env.use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true")
@@ -157,8 +160,6 @@ def main(argv=None):
 
     # platform knobs must land before the first device query initializes
     # the backend (repro.common.env docstring)
-    from repro.common import env
-
     if args.host_devices:
         env.set_host_device_count(args.host_devices)
     if args.platform:

@@ -21,6 +21,9 @@ from repro.train.trainer import Trainer
 
 
 def main():
+    from repro.common.env import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true",

@@ -77,6 +77,55 @@ def _prefill_compiled(params, cfg: ModelConfig, tokens, positions,
                    max_len)
 
 
+# DP mesh: Pallas kernels cannot be partitioned automatically, so every
+# device runs its own lanes of the step under shard_map, with the params
+# replicated and the slot axis split over all mesh axes.
+def _lane_specs(mesh: Any, tree: Any) -> Any:
+    """Specs of the decode cache rules with the slot ("batch") axis over
+    every mesh axis and nothing else split; replicated when the slots do
+    not divide over the devices."""
+    from repro.distributed.sharding import cache_partition_specs
+
+    rules = {"batch": tuple(mesh.axis_names), "heads": None,
+             "kv_heads": None, "kv_seq": None, "state": None}
+    return cache_partition_specs(tree, mesh, rules=rules)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh"))
+def _decode_dp_compiled(params, cfg: ModelConfig, mesh, cache, tokens,
+                        positions):
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import shard_map
+
+    def _local(p, c, t, pos):
+        return decode_step(p, cfg, c, t, pos)
+
+    s = _lane_specs(mesh, {"cache": cache, "tokens": tokens,
+                           "positions": positions})
+    return shard_map(_local, mesh,
+                     in_specs=(P(), s["cache"], s["tokens"], s["positions"]),
+                     out_specs=(s["tokens"], s["cache"]))(params, cache,
+                                                          tokens, positions)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "max_len", "mesh"))
+def _prefill_dp_compiled(params, cfg: ModelConfig, tokens, positions,
+                         max_len: int, mesh):
+    """The batch-1 prefill, replicated on every device of the mesh. A
+    Mosaic kernel in a multi-device jit must sit inside a shard_map even
+    when every operand is replicated (DESIGN.md §10)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import shard_map
+
+    def _local(p, t, pos):
+        return prefill(p, cfg, {"tokens": t, "positions": pos}, max_len)
+
+    return shard_map(_local, mesh, in_specs=(P(), P(), P()),
+                     out_specs=P())(params, tokens, positions)
+
+
 class StepExecutor:
     """Owns params, the batched decode cache and the compiled step fns.
 
@@ -89,8 +138,11 @@ class StepExecutor:
         buckets: prefill bucket ladder (default :data:`DEFAULT_BUCKETS`);
             validated strictly-increasing/positive and clipped to
             ``max_len`` (see :func:`effective_buckets`).
-        mesh: optional device mesh for DP decode (slot axis sharded,
-            params replicated per the name-rule table, DESIGN.md §10).
+        mesh: optional device mesh for DP decode: params replicated, the
+            cache's slot axis split over every mesh axis, and each device
+            runs its own lanes under ``shard_map`` (DESIGN.md §10). When
+            ``num_slots`` does not divide by the mesh size, every device
+            runs every lane.
     """
 
     def __init__(self, cfg: ModelConfig, params: Any, num_slots: int,
@@ -152,21 +204,12 @@ class StepExecutor:
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from repro.distributed.sharding import (
-                cache_partition_specs,
-                params_partition_specs,
-            )
-
-            def _shardings(specs):
-                return jax.tree_util.tree_map(
-                    lambda sp: NamedSharding(mesh, sp), specs,
-                    is_leaf=lambda sp: isinstance(sp, P))
-
-            self.params = jax.device_put(
-                params, _shardings(params_partition_specs(params, mesh)))
-            probe = init_decode_cache(cfg, self.num_slots, self.max_len)
-            self._cache_shardings = _shardings(
-                cache_partition_specs(probe, mesh))
+            self.params = jax.device_put(params, NamedSharding(mesh, P()))
+            probe = jax.eval_shape(
+                lambda: init_decode_cache(cfg, self.num_slots, self.max_len))
+            self._cache_shardings = jax.tree_util.tree_map(
+                lambda sp: NamedSharding(mesh, sp), _lane_specs(mesh, probe),
+                is_leaf=lambda sp: isinstance(sp, P))
         self.cache = None
         self.reset_cache()
 
@@ -236,9 +279,14 @@ class StepExecutor:
         tokens[0, :t] = np.asarray(prompt, np.int32)
         positions = np.full((1, tb), -1, np.int32)
         positions[0, :t] = np.arange(t, dtype=np.int32)
-        logits, cache1 = _prefill_compiled(
-            self.params, self.cfg, jnp.asarray(tokens),
-            jnp.asarray(positions), self.max_len)
+        if self.mesh is None:
+            logits, cache1 = _prefill_compiled(
+                self.params, self.cfg, jnp.asarray(tokens),
+                jnp.asarray(positions), self.max_len)
+        else:
+            logits, cache1 = _prefill_dp_compiled(
+                self.params, self.cfg, jnp.asarray(tokens),
+                jnp.asarray(positions), self.max_len, self.mesh)
         return logits, cache1, tb
 
     def splice(self, slot: int, cache1: Any) -> None:
@@ -268,6 +316,11 @@ class StepExecutor:
         ``[num_slots]`` int32 (idle lanes at :attr:`scratch_position`).
         Returns logits ``[num_slots, 1, V]``.
         """
-        logits, self.cache = _decode_compiled(
-            self.params, self.cfg, self.cache, tokens, positions)
+        if self.mesh is None:
+            logits, self.cache = _decode_compiled(
+                self.params, self.cfg, self.cache, tokens, positions)
+        else:
+            logits, self.cache = _decode_dp_compiled(
+                self.params, self.cfg, self.mesh, self.cache, tokens,
+                positions)
         return logits

@@ -338,7 +338,9 @@ def apply_sketch_plan(
             f"expected trailing dim {plan.input_dim}, got {x.shape}"
         )
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        from repro.kernels.common import default_interpret
+
+        use_pallas = not default_interpret()
     prec = resolve_precision(precision)
     compute_dtype = prec.compute_dtype
     batch_shape = x.shape[:-1]

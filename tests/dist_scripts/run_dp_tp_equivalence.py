@@ -21,6 +21,7 @@ from repro.distributed.sharding import (  # noqa: E402
     logical_rules_context,
     params_partition_specs,
 )
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.train.steps import (  # noqa: E402
     TrainHyper,
     init_train_state,
@@ -48,7 +49,7 @@ for i in range(4):
     losses1.append(float(m["loss"]))
 
 # ---- 2x4 mesh ---------------------------------------------------------------
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with logical_rules_context(mesh) as rules:
     state2 = init_train_state(cfg, jax.random.PRNGKey(0), hyper)
     pspec = params_partition_specs(state2["params"], mesh, rules)

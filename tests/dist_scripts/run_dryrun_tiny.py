@@ -20,6 +20,7 @@ from repro.distributed.sharding import (  # noqa: E402
     logical_rules_context,
     params_partition_specs,
 )
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.train.steps import (  # noqa: E402
     TrainHyper,
     init_train_state,
@@ -27,7 +28,7 @@ from repro.train.steps import (  # noqa: E402
 )
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 for arch in ("qwen3-1.7b", "mixtral-8x7b", "jamba-v0.1-52b", "xlstm-350m"):
     cfg = get_config(arch, smoke=True)

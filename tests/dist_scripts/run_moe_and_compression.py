@@ -16,6 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
 from repro.distributed.sharding import logical_rules_context  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import forward, init_model, moe as moe_mod  # noqa: E402
 from repro.optim.compression import (  # noqa: E402
     compressed_psum_with_feedback,
@@ -40,7 +41,7 @@ batch = {
 }
 logits_local, _ = jax.jit(lambda p, b: forward(p, cfg, b))(params, batch)
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 with logical_rules_context(mesh):
     logits_mesh, _ = jax.jit(lambda p, b: forward(p, cfg, b))(params, batch)
 err = float(jnp.abs(logits_local - logits_mesh).max())
@@ -48,7 +49,7 @@ print("moe mesh parity max err:", err)
 assert err < 2e-3, err
 
 # ---- (b) compressed cross-pod psum -----------------------------------------
-mesh2 = jax.make_mesh((4, 2), ("pod", "data"))
+mesh2 = make_mesh((4, 2), ("pod", "data"))
 grads = jax.random.normal(jax.random.PRNGKey(3), (4, 128)) * 0.1
 
 def body(g, r):

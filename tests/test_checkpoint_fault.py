@@ -93,11 +93,13 @@ def test_elastic_remesh_single_device(tmp_path):
     """Checkpoint written under one topology restores onto another."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.launch.mesh import make_mesh as make_auto_mesh
+
     mgr = CheckpointManager(tmp_path)
     mgr.save(3, _state(3.0))
 
     def make_mesh():
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return make_auto_mesh((1, 1), ("data", "model"))
 
     def make_shardings(mesh):
         return jax.tree_util.tree_map(

@@ -31,3 +31,8 @@ def test_sharded_estimators_and_dp_serving():
     out = _run("run_sharded_estimators.py")
     assert "SHARDED ESTIMATORS OK" in out
     assert "DP decode matches single-device generations" in out
+
+
+def test_dp_decode_split_lanes():
+    out = _run("run_dp_decode_lanes.py")
+    assert "split-lane DP decode matches single-device generations" in out

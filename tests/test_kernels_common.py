@@ -18,15 +18,38 @@ def test_default_interpret_is_the_backend_rule():
     assert kcommon.default_interpret() == (jax.default_backend() != "tpu")
 
 
-def test_all_wrappers_share_one_interpret_rule():
-    """The rm/sketch/ctr ops modules must resolve interpret=None through
-    kernels.common.default_interpret — not a re-derived backend check."""
-    from repro.kernels.ctr_feature import ops as ctr_ops
-    from repro.kernels.rm_feature import ops as rm_ops
-    from repro.kernels.tensor_sketch import ops as ts_ops
+def test_all_wrappers_share_one_interpret_rule(monkeypatch):
+    """The rm/sketch/ctr/structured ops modules must resolve interpret=None
+    through kernels.common.default_interpret AT CALL TIME — a patch of
+    kernels.common reaches every launch, and no wrapper keeps a re-derived
+    backend check or an import-time alias."""
+    import jax.numpy as jnp
 
-    for mod in (rm_ops, ts_ops, ctr_ops):
-        assert mod._default_interpret is kcommon.default_interpret, mod
+    from repro.kernels import structured_feature
+
+    calls = []
+
+    def rule():
+        calls.append(1)
+        return True
+
+    monkeypatch.setattr(kcommon, "default_interpret", rule)
+    x = jnp.ones((8, 8), jnp.float32)
+    w = jnp.ones((1, 8, 8), jnp.float32)
+    deg = jnp.ones((8,), jnp.int32)
+    sc = jnp.ones((8,), jnp.float32)
+    m = jnp.eye(8, dtype=jnp.float32)
+    signs = jnp.ones((1, 1, 8), jnp.float32)     # one stack of d_pad = 8
+    launches = (
+        lambda: rm_feature.rm_feature_fused(x, w, deg, sc),
+        lambda: tensor_sketch.tensor_sketch_fused(x, w, w, deg, m, m, sc),
+        lambda: ctr_feature.ctr_feature_fused(x, w, w, deg, sc),
+        lambda: structured_feature.structured_feature_fused(
+            x, signs, signs, deg, sc),
+    )
+    for i, launch in enumerate(launches):
+        launch()
+        assert len(calls) == i + 1, i
     # rm_attention resolves it lazily; the source-level check keeps the
     # rule from being re-duplicated there.
     import inspect

@@ -139,12 +139,13 @@ def test_moe_shardmap_batch1(monkeypatch):
     """Regression: MoE shard_map with batch=1 (long_500k) falls back to
     replicated tokens instead of failing to shard."""
     from repro.distributed.sharding import logical_rules_context
+    from repro.launch.mesh import make_mesh
 
     cfg = get_config("mixtral-8x7b", smoke=True)
     cfg = dataclasses.replace(cfg, compute_dtype="float32")
     params = init_model(cfg, jax.random.PRNGKey(0))
     tokens = jnp.ones((1, 4), jnp.int32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with logical_rules_context(mesh):
         logits, _ = jax.jit(
             lambda p, b: forward(p, cfg, b))(params, {"tokens": tokens})

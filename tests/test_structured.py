@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core import (
     ExponentialDotProductKernel,
@@ -105,8 +106,18 @@ def test_butterfly_wht_matches_sylvester_matrix():
         assert np.allclose(h @ h.T, m * np.eye(m))      # orthogonal, +-1
         v = jax.random.normal(jax.random.PRNGKey(m), (3, 2, m))
         want = np.asarray(v) @ h                         # H symmetric
-        got = np.asarray(_wht(jnp.asarray(v)))
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+        # two stacks side by side on the lane axis, as the kernel holds
+        # them; the lane rolls only lower inside a Pallas kernel
+        v2 = jnp.asarray(v).reshape(3, 2 * m)
+
+        def kernel(v_ref, o_ref, m=m):
+            o_ref[...] = _wht(v_ref[...], m)
+
+        got = np.asarray(pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(v2.shape, v2.dtype),
+            interpret=True)(v2))
+        np.testing.assert_allclose(got.reshape(3, 2, m), want, rtol=1e-4,
+                                   atol=1e-5)
 
 
 def test_single_column_is_one_rademacher_projection():
