@@ -1,5 +1,4 @@
-"""Roofline rows from the dry-run artifacts (bench-subsystem home), plus
-the shape-level analytic launch costs the obs kernel spans attach.
+"""Roofline rows from the dry-run artifacts (bench-subsystem home).
 
 Reads ``results/dryrun/single/*.json`` (produced by ``python -m
 repro.launch.dryrun``) and emits one row per (arch x shape):
@@ -7,59 +6,15 @@ repro.launch.dryrun``) and emits one row per (arch x shape):
 dry-run hasn't been executed, emits a pointer row instead of failing (the
 dry-run needs the 512-device XLA flag and ~1-2h of compiles).
 
-:func:`launch_cost` is the companion of ``runner.analytic_cost`` for call
-sites that only know SHAPES, not plans: the fused Pallas wrapper ops
-(``kernels/*/ops.py``) attach its FLOPs/HBM-bytes to their ``kernel/*``
-trace spans (repro.obs.trace.kernel_scope), so a Perfetto view of a serve
-trace carries the analytic roofline next to every launch.
-
 ``benchmarks/roofline_bench.py`` is the thin CLI over this module.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import List, Optional
 
-__all__ = ["dryrun_roofline_rows", "launch_cost"]
-
-
-def launch_cost(kernel: str, *, batch: int, d: int, depth: int, f: int,
-                dv: int = 0, t: int = 0, itemsize: int = 4) -> Dict[str, float]:
-    """Analytic FLOPs + HBM bytes of one fused launch, from shapes alone.
-
-    Upper-bound accounting (every feature column at the packed tensor's
-    ``depth``; the per-plan ``runner.analytic_cost`` refines this with the
-    real degree allocation). Families:
-
-    * ``rm_feature`` / ``ctr_feature`` — (batch, feature)-tiled product
-      kernels: one x read, ``n_w`` packed weight tensors, fp32 output.
-    * ``tensor_sketch`` — adds the two [f, f] inverse-DFT operands and the
-      stage-2 matmul FLOPs.
-    * ``rm_attn_fused`` — the fused featurize+attention causal kernel:
-      featurize FLOPs for q and k rows plus the chunked attention GEMMs;
-      bytes stream q/k/v/w once and emit out + the (S, n) decode state
-      (Z never touches HBM — DESIGN.md §13).
-    """
-    if kernel == "rm_attn_fused":
-        rows = batch * t
-        feat_flops = 2.0 * 2 * rows * d * depth * f
-        attn_flops = 4.0 * rows * f * (dv + 1)
-        bytes_moved = (itemsize * (2 * rows * d + depth * f * d)
-                       + 4.0 * rows * 2 * dv
-                       + 4.0 * batch * (f * dv + f))
-        flops = feat_flops + attn_flops
-    else:
-        n_w = 2 if kernel in ("ctr_feature", "tensor_sketch") else 1
-        flops = 2.0 * n_w * batch * d * depth * f
-        weight_elems = n_w * depth * f * d
-        out_cols = 2 * f if kernel == "ctr_feature" else f
-        if kernel == "tensor_sketch":
-            flops += 4.0 * batch * f * f   # stage-2 inverse-DFT matmuls
-            weight_elems += 2 * f * f
-        bytes_moved = (itemsize * (batch * d + weight_elems)
-                       + 4.0 * batch * out_cols)
-    return {"flops": float(flops), "hbm_bytes": float(bytes_moved)}
+__all__ = ["dryrun_roofline_rows"]
 
 
 def dryrun_roofline_rows(results_dir: Optional[Path] = None) -> List[str]:

@@ -61,10 +61,7 @@ def ctr_feature_fused(
     # accumulator pair + both output halves)
     bm, bf = blocks or _get_blocks("ctr_feature", d, k, b, fc, dtype=x.dtype,
                                    weight_tensors=2, accumulators=4)
-    with _kernel_scope("ctr_feature", x=x,
-                       cost=dict(batch=b, d=d, depth=k, f=fc,
-                                 itemsize=jnp.dtype(x.dtype).itemsize),
-                       blocks=[bm, bf], interpret=bool(interpret)):
+    with _kernel_scope("ctr_feature"):
         b_pad = _round_up(max(b, bm), bm)
         f_pad = _round_up(max(fc, bf), bf)
         xp = jnp.pad(xf, ((0, b_pad - b), (0, 0)))

@@ -85,8 +85,7 @@ def _causal_pallas(zq, zk, v, chunk: int, eps: float, interpret: bool):
     zq_p, zk_p, v_p = _pad_t(zq, pad), _pad_t(zk, pad), _pad_t(v, pad)
     n = (t + pad) // chunk
     _, _, s_prev, n_prev = _chunk_states(zk_p, v_p, chunk)
-    with _kernel_scope("rm_attention", x=zq, chunk=chunk,
-                       interpret=bool(interpret)):
+    with _kernel_scope("rm_attention"):
         out = rm_attention_chunked_pallas(
             zq_p.reshape(b * h, t + pad, f),
             zk_p.reshape(b * h, t + pad, f),
@@ -263,16 +262,12 @@ def _fused_pad(q, k, v, kvalid, w, deg_t, scale_t, chunk, block_f):
 def _fused_causal_launch(q, k, v, kvalid, w, deg_t, scale_t, chunk, block_f,
                          eps, interpret):
     """Pallas causal launch; returns (out, s_final, n_final) cropped."""
-    b, h, t, d = q.shape
+    b, h, t, _ = q.shape
     dv = v.shape[-1]
     f = w.shape[1]
     (qf, kf, vf, kval3, w_p, deg, scale, chunk, bf, tp,
      f_pad) = _fused_pad(q, k, v, kvalid, w, deg_t, scale_t, chunk, block_f)
-    with _kernel_scope("rm_attn_fused", x=q,
-                       cost=dict(batch=b * h, t=t, d=d, depth=w.shape[0],
-                                 f=f, dv=dv,
-                                 itemsize=jnp.dtype(q.dtype).itemsize),
-                       blocks=[chunk, bf], interpret=bool(interpret)):
+    with _kernel_scope("rm_attn_fused"):
         out, s, n = rm_fused_attention_pallas(
             qf, kf, vf, kval3, w_p, deg, scale,
             chunk=chunk, block_f=bf, eps=eps, interpret=interpret)
@@ -314,15 +309,11 @@ _fused_causal.defvjp(_fused_causal_fwd, _fused_causal_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _fused_noncausal(q, k, v, kvalid, w, deg_t, scale_t, chunk, block_f,
                      eps, interpret):
-    b, h, t, d = q.shape
+    b, h, t, _ = q.shape
     dv = v.shape[-1]
     (qf, kf, vf, kval3, w_p, deg, scale, chunk, bf, tp,
      f_pad) = _fused_pad(q, k, v, kvalid, w, deg_t, scale_t, chunk, block_f)
-    with _kernel_scope("rm_attn_fused", x=q, mode="noncausal",
-                       cost=dict(batch=b * h, t=t, d=d, depth=w.shape[0],
-                                 f=w.shape[1], dv=dv,
-                                 itemsize=jnp.dtype(q.dtype).itemsize),
-                       blocks=[chunk, bf], interpret=bool(interpret)):
+    with _kernel_scope("rm_attn_fused"):
         s, n = rm_fused_state_pallas(kf, vf, kval3, w_p, deg, scale,
                                      chunk=chunk, block_f=bf,
                                      interpret=interpret)

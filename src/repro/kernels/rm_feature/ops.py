@@ -74,10 +74,7 @@ def rm_feature_fused(
 
     b = xf.shape[0]
     bm, bf = blocks or _get_blocks("rm_feature", d, k, b, f, dtype=x.dtype)
-    with _kernel_scope("rm_feature", x=x,
-                       cost=dict(batch=b, d=d, depth=k, f=f,
-                                 itemsize=jnp.dtype(x.dtype).itemsize),
-                       blocks=[bm, bf], interpret=bool(interpret)):
+    with _kernel_scope("rm_feature"):
         b_pad = _round_up(max(b, bm), bm)
         f_pad = _round_up(max(f, bf), bf)
         xp = jnp.pad(xf, ((0, b_pad - b), (0, 0)))

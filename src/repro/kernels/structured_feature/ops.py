@@ -76,10 +76,7 @@ def structured_feature_fused(
     unit = max(1, 128 // m)
     bs = s if _round_up(bs, unit) >= s else _round_up(bs, unit)
     bf = bs * m
-    with _kernel_scope("structured_feature", x=x,
-                       cost=dict(batch=b, d=m, depth=k, f=cols,
-                                 itemsize=jnp.dtype(x.dtype).itemsize),
-                       blocks=[bm, bf], interpret=bool(interpret)):
+    with _kernel_scope("structured_feature"):
         b_pad = _round_up(max(b, bm), bm)
         s_pad = _round_up(max(s, bs), bs)
         xp = jnp.pad(xf, ((0, b_pad - b), (0, 0)))

@@ -68,10 +68,7 @@ def tensor_sketch_fused(
         bm = int(blocks[0])
     else:
         bm = _get_batch_block("tensor_sketch", d, k, f_pad, b, dtype=x.dtype)
-    with _kernel_scope("tensor_sketch", x=x,
-                       cost=dict(batch=b, d=d, depth=k, f=fs,
-                                 itemsize=jnp.dtype(x.dtype).itemsize),
-                       blocks=[bm, f_pad], interpret=bool(interpret)):
+    with _kernel_scope("tensor_sketch"):
         b_pad = _round_up(max(b, bm), bm)
         xp = jnp.pad(xf, ((0, b_pad - b), (0, 0)))
         pf = f_pad - fs

@@ -15,10 +15,11 @@ replicated estimator params (DESIGN.md §10) — pair with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on CPU.
 
 Observability (docs/observability.md): ``--trace-out trace.jsonl`` streams
-the request lifecycle + ``kernel/*`` spans as JSONL (summarize or convert
-with ``python -m repro.obs``), ``--metrics-out metrics.json`` snapshots the
-TTFT / token-latency / tokens-per-sec histograms, and ``--drift-every N``
-runs the online (eps, delta) Gram-drift check every N decode iterations.
+the request lifecycle and the scheduler's step spans as JSONL (summarize
+or convert with ``python -m repro.obs``), ``--metrics-out metrics.json``
+snapshots the TTFT / token-latency / tokens-per-sec histograms, and
+``--drift-every N`` runs the online (eps, delta) Gram-drift check every N
+decode iterations.
 """
 from __future__ import annotations
 
@@ -139,7 +140,8 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--trace-out", default=None, metavar="FILE",
-                    help="stream a JSONL lifecycle + kernel-span trace "
+                    help="stream a JSONL trace of the request "
+                         "lifecycle and the scheduler's step spans "
                          "(inspect with python -m repro.obs)")
     ap.add_argument("--metrics-out", default=None, metavar="FILE",
                     help="write the metrics snapshot (TTFT/latency/tok-s "
@@ -220,8 +222,7 @@ def main(argv=None):
                 print("[serve] --drift-every ignored: attention mode is "
                       "not rm-family")
         obs = obs_mod.Obs(trace_path=args.trace_out, drift=drift,
-                          drift_every=args.drift_every,
-                          install_kernel_tracing=True)
+                          drift_every=args.drift_every)
 
     engine = make_engine(
         args.arch, num_slots=args.slots, max_len=args.max_len,

@@ -41,8 +41,8 @@ def main():
     ap.add_argument("--model-parallel", type=int, default=1,
                     help="TP size for --mesh host")
     ap.add_argument("--trace-out", default=None, metavar="FILE",
-                    help="stream a JSONL train/step + kernel-span trace "
-                         "(inspect with python -m repro.obs)")
+                    help="stream a JSONL trace of the train/step "
+                         "spans (inspect with python -m repro.obs)")
     ap.add_argument("--metrics-out", default=None, metavar="FILE",
                     help="write the metrics snapshot (step-time histogram, "
                          "loss gauge) as JSON")
@@ -93,8 +93,7 @@ def main():
             print("[train] --drift-every ignored: attention mode is not "
                   "rm-family")
         obs = obs_mod.Obs(trace_path=args.trace_out, drift=drift,
-                          drift_every=args.drift_every,
-                          install_kernel_tracing=True)
+                          drift_every=args.drift_every)
 
     trainer = Trainer(cfg, hyper, data, ckpt_dir=args.ckpt_dir, mesh=mesh,
                       obs=obs)

@@ -5,14 +5,14 @@ Public surface:
 
 * :class:`Obs` / :data:`NOOP` / :func:`resolve` — the facade every
   instrumented layer threads (``ServingEngine(obs=...)``,
-  ``Trainer(obs=...)``); ``None`` resolves to a zero-overhead no-op.
+  ``Trainer(obs=...)``); ``None`` resolves to a no-op that records nothing.
 * :mod:`repro.obs.clock` — the ONE monotonic clock behind bench timings,
   span durations and serving latencies (tests inject ``FakeClock``).
 * :class:`MetricsRegistry` (counters/gauges/histograms, p50/p90/p99
   summaries, provenance-stamped JSON snapshots).
 * :class:`Tracer` + :func:`chrome_trace` (JSONL spans/events, Perfetto
-  export) and :func:`kernel_scope` (named_scope/TraceAnnotation + analytic
-  launch costs inside the four fused Pallas wrapper ops).
+  export); every ``Obs.span`` is also a ``repro.<name>`` span in a JAX
+  profile. :func:`kernel_scope` names the fused Pallas wrapper ops.
 * :class:`DriftMonitor` — the paper's concentration bound as a live SLO.
 
 CLI: ``python -m repro.obs {summarize,diff,chrome} trace.jsonl``.
@@ -25,8 +25,6 @@ from repro.obs.trace import (
     TRACE_SCHEMA,
     Tracer,
     chrome_trace,
-    current_tracer,
-    install_tracer,
     kernel_scope,
     read_trace,
     write_chrome,
@@ -36,6 +34,6 @@ __all__ = [
     "Obs", "NoopObs", "NOOP", "resolve", "clock",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "Tracer", "TRACE_SCHEMA", "chrome_trace", "read_trace", "write_chrome",
-    "install_tracer", "current_tracer", "kernel_scope",
+    "kernel_scope",
     "DriftMonitor", "DriftReport", "hoeffding_eps",
 ]

@@ -1,19 +1,23 @@
 """The ``Obs`` facade: one object threading metrics + tracing + drift
 monitoring through the hot paths, and a true no-op when disabled.
 
-Every instrumented layer (``ServingEngine``, ``Trainer``, the launch CLIs)
-takes ``obs=None`` and resolves it through :func:`resolve`: ``None`` maps
-to the shared :data:`NOOP` singleton whose every method is a ``pass`` (and
-whose ``span`` returns a pre-built null context), so the disabled path
-costs one attribute call per site — no branches at call sites, no config
-flags, and decode outputs stay bit-identical because observability never
-touches a jax value (tests/test_serve_obs.py pins both properties).
+Every instrumented layer (``ServingEngine``, ``Scheduler``, ``Trainer``,
+the launch CLIs) takes ``obs=None`` and resolves it through
+:func:`resolve`: ``None`` maps to the shared :data:`NOOP` singleton, so
+there are no branches at call sites and no config flags, and decode
+outputs stay bit-identical because observability never touches a jax
+value (tests/test_serve_obs.py pins both properties).
+
+Every span, enabled or not, also enters
+``jax.profiler.TraceAnnotation("repro." + name)``: a profile taken around
+the program shows its spans on the host plane, on the clock of the device
+operations. With no profiler session open the annotation records nothing
+and costs one native object per span; span attributes reach it only while
+a session is open.
 
 An enabled ``Obs`` owns a :class:`~repro.obs.metrics.MetricsRegistry` and
 a :class:`~repro.obs.trace.Tracer` on ONE clock (injectable — tests use
-``FakeClock`` for exact lifecycle assertions), optionally installs its
-tracer as the process-ambient kernel tracer (so the four fused Pallas
-wrapper ops contribute ``kernel/*`` spans), and optionally drives a
+``FakeClock`` for exact lifecycle assertions), and optionally drives a
 :class:`~repro.obs.drift.DriftMonitor` every ``drift_every`` ticks of the
 serving/training loop.
 """
@@ -22,21 +26,30 @@ from __future__ import annotations
 import contextlib
 from typing import Any, Callable, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import clock as _clock
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Tracer, install_tracer
+from repro.obs.trace import Tracer
 
 __all__ = ["Obs", "NoopObs", "NOOP", "resolve"]
 
-_NULL_CTX = contextlib.nullcontext()
+
+def _annotation(name: str, attrs: Dict[str, Any]) -> TraceAnnotation:
+    """The profiler span ``repro.<name>``; ``attrs`` ride along only while
+    a profiler session is open, so a closed one costs no encoding."""
+    if attrs and TraceAnnotation.is_enabled():
+        return TraceAnnotation("repro." + name, **attrs)
+    return TraceAnnotation("repro." + name)
 
 
 class NoopObs:
-    """Disabled observability: every hook is a no-op, ``now`` still ticks.
+    """Disabled observability: nothing is recorded, ``now`` still ticks.
 
     ``now()`` stays a real monotonic read so engine timestamp fields keep
-    their meaning whether or not observability is on; everything else does
-    nothing and allocates nothing.
+    their meaning whether or not observability is on; ``span`` is only the
+    profiler annotation, which records nothing unless a profiler session
+    is open; every other hook does nothing.
     """
 
     enabled = False
@@ -49,7 +62,7 @@ class NoopObs:
         pass
 
     def span(self, name: str, **attrs: Any):
-        return _NULL_CTX
+        return _annotation(name, attrs)
 
     def counter(self, name: str, amount: float = 1.0) -> None:
         pass
@@ -86,10 +99,6 @@ class Obs:
         drift: a ``DriftMonitor`` to drive from the serving/training loop.
         drift_every: run ``drift.check()`` every N ``tick_drift`` calls
             (0 disables ticking even with a monitor attached).
-        install_kernel_tracing: make this tracer the process-ambient
-            kernel tracer for the lifetime of the object (the fused Pallas
-            wrapper ops then record ``kernel/*`` spans with analytic
-            FLOPs/HBM-bytes). Restore/clear happens in ``close()``.
     """
 
     enabled = True
@@ -97,8 +106,7 @@ class Obs:
     def __init__(self, trace_path=None,
                  clock: Optional[Callable[[], float]] = None,
                  provenance: Optional[Dict] = None,
-                 drift=None, drift_every: int = 0,
-                 install_kernel_tracing: bool = False):
+                 drift=None, drift_every: int = 0):
         self._now = clock if clock is not None else _clock.monotonic
         self.metrics = MetricsRegistry(now=self._now)
         self.tracer = Tracer(path=trace_path, now=self._now,
@@ -106,11 +114,6 @@ class Obs:
         self.drift = drift
         self.drift_every = int(drift_every)
         self._drift_tick = 0
-        self._prev_tracer = None
-        self._installed = False
-        if install_kernel_tracing:
-            self._prev_tracer = install_tracer(self.tracer)
-            self._installed = True
 
     # -- clock / trace / metrics passthroughs --------------------------------
     def now(self) -> float:
@@ -119,8 +122,11 @@ class Obs:
     def event(self, name: str, **attrs: Any) -> None:
         self.tracer.event(name, **attrs)
 
+    @contextlib.contextmanager
     def span(self, name: str, **attrs: Any):
-        return self.tracer.span(name, **attrs)
+        """A JSONL span record and the profiler span around the body."""
+        with _annotation(name, attrs), self.tracer.span(name, **attrs) as t:
+            yield t
 
     def counter(self, name: str, amount: float = 1.0) -> None:
         self.metrics.counter(name).inc(amount)
@@ -174,8 +180,5 @@ class Obs:
         self.metrics.write_json(path)
 
     def close(self) -> None:
-        """Flush the trace file and restore the ambient kernel tracer."""
-        if self._installed:
-            install_tracer(self._prev_tracer)
-            self._installed = False
+        """Flush the trace file."""
         self.tracer.close()

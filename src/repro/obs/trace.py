@@ -15,18 +15,8 @@ record list to the Chrome ``traceEvents`` format, so any trace opens in
 Perfetto / ``chrome://tracing`` (spans become complete "X" slices, events
 instant "i" marks).
 
-Kernel launches are traced through the AMBIENT tracer: the four fused
-Pallas wrapper ops (rm_feature, tensor_sketch, ctr_feature, rm_attention)
-run under :func:`kernel_scope`, which always applies ``jax.named_scope``
-(so device profiles / HLO dumps carry the kernel name at zero cost) and —
-only when a tracer is installed via ``install_tracer`` — additionally
-wraps the launch in ``jax.profiler.TraceAnnotation`` and records a span
-with the analytic FLOPs/HBM-bytes for that launch shape
-(``repro.bench.roofline.launch_cost``). Inside a ``jit`` trace the wrapper
-body runs once per compile, not per call; such spans carry
-``"traced": true`` and their duration is TRACE time — per-call device
-timing belongs to the jax profiler, the span marks which kernels a
-compilation touched and what they cost analytically.
+The fused Pallas wrapper ops run under :func:`kernel_scope`, a
+``jax.named_scope``: device profiles and HLO dumps carry the kernel name.
 """
 from __future__ import annotations
 
@@ -35,10 +25,12 @@ import json
 from pathlib import Path
 from typing import Any, Callable, Dict, Iterable, List, Optional
 
+import jax
+
 from repro.obs import clock as _clock
 
-__all__ = ["TRACE_SCHEMA", "Tracer", "install_tracer", "current_tracer",
-           "kernel_scope", "chrome_trace", "read_trace", "write_chrome"]
+__all__ = ["TRACE_SCHEMA", "Tracer", "kernel_scope", "chrome_trace",
+           "read_trace", "write_chrome"]
 
 TRACE_SCHEMA = "repro.obs.trace/v1"
 
@@ -112,62 +104,11 @@ class Tracer:
         return out if name is None else [r for r in out if r["name"] == name]
 
 
-# ---------------------------------------------------------------------------
-# ambient tracer for the kernel wrappers
-# ---------------------------------------------------------------------------
-_CURRENT: Optional[Tracer] = None
-
-
-def install_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
-    """Set (or clear, with None) the process-ambient tracer.
-
-    The kernel wrappers consult this instead of taking an ``obs`` argument
-    — their call signatures stay pure jax, and the disabled path is one
-    global ``is None`` check. Returns the previous tracer so callers can
-    restore it (``Obs.activate`` does).
-    """
-    global _CURRENT
-    prev = _CURRENT
-    _CURRENT = tracer
-    return prev
-
-
-def current_tracer() -> Optional[Tracer]:
-    return _CURRENT
-
-
-@contextlib.contextmanager
-def kernel_scope(kernel: str, x=None, cost: Optional[Dict] = None,
-                 **attrs: Any):
-    """Name a fused-kernel launch for device profiles and the obs trace.
-
-    Always enters ``jax.named_scope(kernel)`` — the HLO ops produced inside
-    carry the kernel name, so TPU/XLA profiles group by kernel family with
-    no tracer installed and no measurable overhead. With an ambient tracer,
-    also enters ``jax.profiler.TraceAnnotation`` (host profiler timeline)
-    and records a ``kernel/<name>`` span: ``x`` (any operand) marks the
-    span ``traced=True`` when the launch is being traced under jit rather
-    than executed eagerly, and ``cost`` (shape kwargs for
-    ``repro.bench.roofline.launch_cost``) attaches the analytic
-    FLOPs/HBM-bytes — computed ONLY when a tracer is installed, so the
-    disabled path never pays it.
-    """
-    import jax
-
-    tracer = _CURRENT
-    if tracer is None:
-        with jax.named_scope(kernel):
-            yield
-        return
-    traced = isinstance(x, jax.core.Tracer) if x is not None else False
-    if cost is not None:
-        from repro.bench.roofline import launch_cost
-
-        attrs.update(launch_cost(kernel, **cost))
-    with jax.named_scope(kernel), \
-            jax.profiler.TraceAnnotation(f"repro.{kernel}"), \
-            tracer.span(f"kernel/{kernel}", traced=traced, **attrs):
-        yield
+def kernel_scope(kernel: str):
+    """Name a fused-kernel launch: the HLO operations produced inside carry
+    ``kernel`` in their metadata, so device profiles group by kernel
+    family."""
+    return jax.named_scope(kernel)
 
 
 # ---------------------------------------------------------------------------
@@ -187,9 +128,8 @@ def chrome_trace(records: Iterable[Dict]) -> Dict:
     """Convert obs records to the Chrome ``traceEvents`` JSON format.
 
     Spans become complete ("ph": "X") slices and events instant ("ph": "i")
-    marks, all on one pid/tid; ``attrs`` ride along as ``args`` so Perfetto
-    shows the analytic FLOPs/HBM-bytes on kernel slices. The meta record
-    maps to process metadata.
+    marks, all on one pid/tid; ``attrs`` ride along as ``args``. The meta
+    record maps to process metadata.
     """
     out: List[Dict] = []
     for rec in records:

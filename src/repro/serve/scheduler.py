@@ -39,7 +39,12 @@ spans → ``request/finish``, plus ``request/evict``/``evict`` spans and
 ``serve/restart`` events), the ``serve/queue_age_s`` gauge (age of the
 oldest queued request) and the TTFT / inter-token / tokens-per-sec
 histograms, all on the injectable ``repro.obs`` clock — the whole
-scheduler runs deterministically under ``FakeClock``.
+scheduler runs deterministically under ``FakeClock``. The host phases of
+a tick are spans too: ``step`` holds the whole tick; each sampled token
+has a ``sample`` span (key, logit slice, ``sample_token``) and a ``fetch``
+span (the device-to-host read of the token, counted in
+``serve/host_syncs``). Every span is also a ``repro.<name>`` span in a
+JAX profile, on the clock of the device operations.
 """
 from __future__ import annotations
 
@@ -216,17 +221,18 @@ class Scheduler:
         every in-flight request onto a fresh decode cache (restart-from-
         scratch recovery) instead of propagating, up to the budget.
         """
-        self._step_idx += 1
-        info = StepInfo(t_start=self.obs.now())
-        try:
-            self._admit_phase(info)
-            self._decode_phase(info)
-        except Exception as e:  # noqa: BLE001 - bounded restart semantics
-            if self.restarts >= self.max_restarts:
-                raise
-            self.restarts += 1
-            self._recover(info, repr(e))
-        info.t_end = self.obs.now()
+        with self.obs.span("step"):
+            self._step_idx += 1
+            info = StepInfo(t_start=self.obs.now())
+            try:
+                self._admit_phase(info)
+                self._decode_phase(info)
+            except Exception as e:  # noqa: BLE001 - bounded restart semantics
+                if self.restarts >= self.max_restarts:
+                    raise
+                self.restarts += 1
+                self._recover(info, repr(e))
+            info.t_end = self.obs.now()
         return info
 
     def evict(self, slot: int, reason: str = "preempted") -> Request:
@@ -283,6 +289,13 @@ class Scheduler:
     def _request_key(self, rid: int, token_idx: int) -> jax.Array:
         return jax.random.fold_in(
             jax.random.fold_in(self._base_key, rid), token_idx)
+
+    def _fetch(self, sampled: jax.Array) -> int:
+        """The one device-to-host read of a sampled token."""
+        with self.obs.span("fetch"):
+            tok = int(sampled[0])
+        self.obs.counter("serve/host_syncs")
+        return tok
 
     def _requeue(self, request: Request) -> None:
         rid = request.request_id
@@ -354,9 +367,10 @@ class Scheduler:
         info.admitted.append(rid)
         # first generated token from the LAST REAL prefill logit, sampled
         # on the request's own key stream (token index 0)
-        tok = sample_token(logits[:, t - 1], self._request_key(rid, 0),
-                           req.temperature)
-        tok_i = int(tok[0])
+        with self.obs.span("sample"):
+            tok = sample_token(logits[:, t - 1], self._request_key(rid, 0),
+                               req.temperature)
+        tok_i = self._fetch(tok)
         state.generated.append(tok_i)
         state.t_first_token = self.obs.now()
         state.t_tokens.append(state.t_first_token)
@@ -392,10 +406,12 @@ class Scheduler:
                 i = state.slot
                 req = state.request
                 tok_idx = len(state.generated)
-                tok = int(sample_token(
-                    logits[i:i + 1, 0],
-                    self._request_key(req.request_id, tok_idx),
-                    req.temperature)[0])
+                with self.obs.span("sample"):
+                    sampled = sample_token(
+                        logits[i:i + 1, 0],
+                        self._request_key(req.request_id, tok_idx),
+                        req.temperature)
+                tok = self._fetch(sampled)
                 state.generated.append(tok)
                 t_tok = self.obs.now()
                 self.obs.histogram("serve/inter_token_s",

@@ -14,9 +14,8 @@ from repro.obs import (
     Tracer,
     chrome_trace,
     clock,
-    current_tracer,
     hoeffding_eps,
-    install_tracer,
+    kernel_scope,
     read_trace,
     resolve,
 )
@@ -136,64 +135,61 @@ def test_chrome_trace_shapes():
     assert all("ts" in e for e in chrome["traceEvents"][1:])
 
 
-def test_ambient_tracer_install_restore():
-    assert current_tracer() is None
-    tr = Tracer(now=clock.FakeClock(), provenance=PROV)
-    prev = install_tracer(tr)
-    try:
-        assert prev is None and current_tracer() is tr
-    finally:
-        install_tracer(prev)
-    assert current_tracer() is None
-
-
-def test_kernel_scope_records_span_with_analytic_cost():
+def test_kernel_scope_names_the_scope():
+    """The fused wrapper ops' scope reaches the HLO metadata."""
     import jax
     import jax.numpy as jnp
 
-    from repro.obs import kernel_scope
+    def f(x):
+        with kernel_scope("rm_feature"):
+            return jnp.sin(x) * 2.0
 
-    x = jnp.ones((4, 8), jnp.float32)
-    # no tracer: pure named_scope, no records anywhere
-    with kernel_scope("rm_feature", x=x):
-        pass
-
-    tr = Tracer(now=clock.FakeClock(), provenance=PROV)
-    prev = install_tracer(tr)
-    try:
-        with kernel_scope("rm_feature", x=x,
-                          cost=dict(batch=4, d=8, depth=3, f=16)):
-            pass
-    finally:
-        install_tracer(prev)
-    (sp,) = tr.spans("kernel/rm_feature")
-    assert sp["attrs"]["traced"] is False
-    assert sp["attrs"]["flops"] > 0 and sp["attrs"]["hbm_bytes"] > 0
+    hlo = jax.jit(f).lower(jnp.ones((4, 8))).as_text(debug_info=True)
+    assert "rm_feature/" in hlo
 
 
-def test_fused_wrapper_emits_kernel_span():
-    """estimate_gram(use_pallas=True) runs the rm_feature fused wrapper,
-    which must contribute a kernel/rm_feature span with launch costs when a
-    tracer is ambient — and nothing when none is installed."""
+def _profiled(tmp_path, body):
+    """Run ``body`` under a JAX profiler session on the CPU; the host
+    events of the profile as ``{name: [(start_ns, end_ns, stats)]}``."""
     import jax
 
-    from repro.core import ExponentialDotProductKernel, make_feature_map
-
-    fm = make_feature_map(ExponentialDotProductKernel(), 4, 16,
-                          jax.random.PRNGKey(0))
-    X = np.random.default_rng(0).standard_normal((4, 4)).astype(np.float32)
-    X *= 0.2
-
-    G0 = np.asarray(fm.estimate_gram(X, use_pallas=True))
-    tr = Tracer(now=clock.FakeClock(), provenance=PROV)
-    prev = install_tracer(tr)
+    jax.profiler.start_trace(str(tmp_path))
     try:
-        G1 = np.asarray(fm.estimate_gram(X, use_pallas=True))
+        body()
     finally:
-        install_tracer(prev)
-    np.testing.assert_array_equal(G0, G1)  # tracing never changes values
-    spans = tr.spans("kernel/rm_feature")
-    assert spans and spans[0]["attrs"]["flops"] > 0
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats)))
+    return out
+
+
+def test_spans_reach_the_profiler(tmp_path):
+    """Disabled and enabled spans both land on the profile's host plane as
+    ``repro.<name>``, nested as they ran, attributes as event stats; the
+    enabled Obs still keeps its JSONL record."""
+    obs = Obs(clock=clock.FakeClock(), provenance=PROV)
+
+    def body():
+        with NOOP.span("outer", slot=3):
+            with obs.span("inner", bucket=32):
+                pass
+
+    events = _profiled(tmp_path, body)
+    ((o0, o1, o_stats),) = events["repro.outer"]
+    ((i0, i1, i_stats),) = events["repro.inner"]
+    assert o0 <= i0 <= i1 <= o1
+    assert o_stats == {"slot": 3} and i_stats == {"bucket": 32}
+    (sp,) = obs.tracer.spans("inner")
+    assert sp["attrs"] == {"bucket": 32}
+    obs.close()
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +211,11 @@ def test_noop_is_inert():
     NOOP.tick_drift()
     with NOOP.span("s", a=1):
         pass
-    assert NOOP.span("a") is NOOP.span("b")  # shared null context
+    # a span is only the profiler's annotation, which records nothing
+    # unless a profiler session is open
+    import jax
+
+    assert isinstance(NOOP.span("a"), jax.profiler.TraceAnnotation)
     assert NOOP.now() <= NOOP.now()
 
 
@@ -229,14 +229,6 @@ def test_obs_shares_one_clock():
     t1 = obs.now()
     assert t1 - t0 == 4.0            # every read came off the same clock
     obs.close()
-
-
-def test_obs_installs_and_restores_kernel_tracer():
-    obs = Obs(clock=clock.FakeClock(), provenance=PROV,
-              install_kernel_tracing=True)
-    assert current_tracer() is obs.tracer
-    obs.close()
-    assert current_tracer() is None
 
 
 # ---------------------------------------------------------------------------

@@ -159,3 +159,54 @@ def test_checker_accepts_truncated_inflight_requests():
         _ev("request/admit", request_id=0, slot=0),
     ]
     assert check_request_lifecycles(records) == []
+
+
+def test_step_span_tree_on_fake_clock(setup):
+    """Each decode step with k busy lanes holds k ``sample`` and k
+    ``fetch`` spans inside ``decode/step`` inside ``step``; a first token's
+    pair sits in its tick outside any decode step; ``serve/host_syncs``
+    counts the fetches, one per emitted token."""
+    cfg, params = setup
+    obs = Obs(clock=clock.FakeClock(), provenance=PROV)
+    sched = Scheduler(cfg, params, num_slots=2, max_len=32, rng_seed=0,
+                      obs=obs)
+    rng = np.random.default_rng(1)
+    for i, n_new in enumerate((3, 1, 4, 2)):
+        sched.submit(Request(request_id=i,
+                             prompt=rng.integers(0, VOCAB, size=4 + i),
+                             max_new_tokens=n_new))
+    infos = []
+    while sched.pending():
+        infos.append(sched.step())
+    obs.close()
+
+    def spans(name):
+        return sorted((sp["ts_us"], sp["ts_us"] + sp["dur_us"])
+                      for sp in obs.tracer.spans(name))
+
+    def inside(inner, a, b):
+        return [(x, y) for x, y in inner if a < x and y < b]
+
+    ticks, decodes = spans("step"), spans("decode/step")
+    samples, fetches = spans("sample"), spans("fetch")
+    assert len(ticks) == len(infos)
+    busy = [i for i in infos if i.active]
+    assert len(decodes) == len(busy) > 0
+    for (a, b), info in zip(decodes, busy):
+        assert any(x < a and b < y for x, y in ticks)
+        assert len(inside(samples, a, b)) == info.active
+        assert len(inside(fetches, a, b)) == info.active
+    admitted = sum(len(i.admitted) for i in infos)
+    outside = [s for s in samples + fetches
+               if not any(a < s[0] and s[1] < b for a, b in decodes)]
+    assert len(outside) == 2 * admitted
+    for s0, s1 in samples + fetches:
+        assert any(a < s0 and s1 < b for a, b in ticks)
+    # a sample is followed by its fetch, before anything else is sampled
+    order = sorted([(a, "sample") for a, _ in samples]
+                   + [(a, "fetch") for a, _ in fetches])
+    assert [k for _, k in order] == ["sample", "fetch"] * len(samples)
+    tokens = sum(i.new_tokens for i in infos)
+    assert len(fetches) == tokens
+    snap = obs.metrics.snapshot(provenance=PROV)
+    assert snap["counters"]["serve/host_syncs"] == tokens

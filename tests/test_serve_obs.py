@@ -104,8 +104,7 @@ def test_obs_disabled_is_bit_identical(setup):
     instrumentation never touches a jax value."""
     cfg, params = setup
     _, done_off = _run_two_requests(cfg, params, None)
-    obs = Obs(clock=clock.FakeClock(), provenance=PROV,
-              install_kernel_tracing=True)
+    obs = Obs(clock=clock.FakeClock(), provenance=PROV)
     _, done_on = _run_two_requests(cfg, params, obs)
     obs.close()
     assert {i: s.generated for i, s in done_off.items()} == \
