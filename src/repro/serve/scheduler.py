@@ -40,11 +40,15 @@ spans → ``request/finish``, plus ``request/evict``/``evict`` spans and
 oldest queued request) and the TTFT / inter-token / tokens-per-sec
 histograms, all on the injectable ``repro.obs`` clock — the whole
 scheduler runs deterministically under ``FakeClock``. The host phases of
-a tick are spans too: ``step`` holds the whole tick; each sampled token
-has a ``sample`` span (key, logit slice, ``sample_token``) and a ``fetch``
-span (the device-to-host read of the token, counted in
-``serve/host_syncs``). Every span is also a ``repro.<name>`` span in a
-JAX profile, on the clock of the device operations.
+a tick are spans too: ``step`` holds the whole tick; each decode step has
+one ``sample`` span (the lanes' keys, made on the device, and one
+``sample_token`` over all ``num_slots`` lanes; attribute ``lanes``, the
+busy ones) and one ``fetch`` span (the one device-to-host read of the
+step's ``[num_slots]`` tokens), and each admission its own pair for the
+first token. ``serve/host_syncs`` counts the fetches and
+``serve/sampled_lanes`` the busy lanes those calls served. Every span is
+also a ``repro.<name>`` span in a JAX profile, on the clock of the device
+operations.
 """
 from __future__ import annotations
 
@@ -63,6 +67,14 @@ from repro.serve.executor import StepExecutor
 from repro.serve.sampler import sample_token
 
 __all__ = ["Scheduler", "StepInfo"]
+
+
+@jax.jit
+def _lane_keys(base: jax.Array, ids: jax.Array) -> jax.Array:
+    """``[2, n]`` uint32 (request id, token index) -> ``[n]`` keys, each
+    ``fold_in(fold_in(base, rid), tok_idx)``: one program for a step."""
+    return jax.vmap(lambda rid, idx: jax.random.fold_in(
+        jax.random.fold_in(base, rid), idx))(ids[0], ids[1])
 
 
 @dataclasses.dataclass
@@ -286,16 +298,23 @@ class Scheduler:
         return self.executor.tier_features(
             self.accuracy_tiers[req.accuracy_tier])
 
-    def _request_key(self, rid: int, token_idx: int) -> jax.Array:
-        return jax.random.fold_in(
-            jax.random.fold_in(self._base_key, rid), token_idx)
-
-    def _fetch(self, sampled: jax.Array) -> int:
-        """The one device-to-host read of a sampled token."""
+    def _sample(self, logits: jax.Array, ids: np.ndarray, temperature,
+                lanes: int) -> np.ndarray:
+        """Sample every row of ``logits`` ``[n, V]`` on its request's key
+        stream (``ids``: ``[2, n]`` request ids and token indices) in one
+        ``sample_token`` call, then read the ``[n]`` tokens back in one
+        device-to-host read. ``lanes`` rows are busy; the rest are
+        ignored."""
+        with self.obs.span("sample", lanes=lanes):
+            # resolved at call time: a patched module-level sample_token
+            # sees every lane of every step
+            sampled = sample_token(logits, _lane_keys(self._base_key, ids),
+                                   temperature)
+        self.obs.counter("serve/sampled_lanes", lanes)
         with self.obs.span("fetch"):
-            tok = int(sampled[0])
+            toks = np.asarray(sampled)
         self.obs.counter("serve/host_syncs")
-        return tok
+        return toks
 
     def _requeue(self, request: Request) -> None:
         rid = request.request_id
@@ -367,10 +386,9 @@ class Scheduler:
         info.admitted.append(rid)
         # first generated token from the LAST REAL prefill logit, sampled
         # on the request's own key stream (token index 0)
-        with self.obs.span("sample"):
-            tok = sample_token(logits[:, t - 1], self._request_key(rid, 0),
-                               req.temperature)
-        tok_i = self._fetch(tok)
+        tok_i = int(self._sample(logits[:, t - 1],
+                                 np.array([[rid], [0]], np.uint32),
+                                 req.temperature, 1)[0])
         state.generated.append(tok_i)
         state.t_first_token = self.obs.now()
         state.t_tokens.append(state.t_first_token)
@@ -402,18 +420,25 @@ class Scheduler:
         with self.obs.span("decode/step", active=len(active)):
             logits = self.executor.decode(jnp.asarray(self._tokens),
                                           jnp.asarray(self._positions))
+            # every lane is sampled (idle ones on a dummy key, their
+            # tokens ignored), so the shapes never change
+            ids = np.zeros((2, self.num_slots), np.uint32)
+            temps = np.zeros((self.num_slots,), np.float32)
+            for state in active:
+                ids[:, state.slot] = (state.request.request_id,
+                                      len(state.generated))
+                temps[state.slot] = state.request.temperature
+            # one float when the busy lanes agree: all-greedy compiles
+            # only the argmax
+            shared = {s.request.temperature for s in active}
+            temperature = float(shared.pop()) if len(shared) == 1 else temps
+            toks = self._sample(logits[:, 0], ids, temperature, len(active))
+            t_tok = self.obs.now()
             for state in list(active):
                 i = state.slot
                 req = state.request
-                tok_idx = len(state.generated)
-                with self.obs.span("sample"):
-                    sampled = sample_token(
-                        logits[i:i + 1, 0],
-                        self._request_key(req.request_id, tok_idx),
-                        req.temperature)
-                tok = self._fetch(sampled)
+                tok = int(toks[i])
                 state.generated.append(tok)
-                t_tok = self.obs.now()
                 self.obs.histogram("serve/inter_token_s",
                                    t_tok - state.t_tokens[-1])
                 state.t_tokens.append(t_tok)

@@ -162,10 +162,13 @@ def test_checker_accepts_truncated_inflight_requests():
 
 
 def test_step_span_tree_on_fake_clock(setup):
-    """Each decode step with k busy lanes holds k ``sample`` and k
-    ``fetch`` spans inside ``decode/step`` inside ``step``; a first token's
-    pair sits in its tick outside any decode step; ``serve/host_syncs``
-    counts the fetches, one per emitted token."""
+    """Each decode step with k busy lanes holds one ``sample`` span
+    (``lanes == k``) and one ``fetch`` span inside ``decode/step`` inside
+    ``step``; each admission's first token has its own pair in its tick
+    outside any decode step; ``serve/host_syncs`` counts the fetches, one
+    per busy decode step and one per admission, and
+    ``serve/sampled_lanes`` the lanes they served, one per emitted
+    token."""
     cfg, params = setup
     obs = Obs(clock=clock.FakeClock(), provenance=PROV)
     sched = Scheduler(cfg, params, num_slots=2, max_len=32, rng_seed=0,
@@ -181,32 +184,37 @@ def test_step_span_tree_on_fake_clock(setup):
     obs.close()
 
     def spans(name):
-        return sorted((sp["ts_us"], sp["ts_us"] + sp["dur_us"])
+        return sorted((sp["ts_us"], sp["ts_us"] + sp["dur_us"],
+                       sp["attrs"].get("lanes"))
                       for sp in obs.tracer.spans(name))
 
     def inside(inner, a, b):
-        return [(x, y) for x, y in inner if a < x and y < b]
+        return [s for s in inner if a < s[0] and s[1] < b]
 
     ticks, decodes = spans("step"), spans("decode/step")
     samples, fetches = spans("sample"), spans("fetch")
     assert len(ticks) == len(infos)
     busy = [i for i in infos if i.active]
     assert len(decodes) == len(busy) > 0
-    for (a, b), info in zip(decodes, busy):
-        assert any(x < a and b < y for x, y in ticks)
-        assert len(inside(samples, a, b)) == info.active
-        assert len(inside(fetches, a, b)) == info.active
+    assert any(i.active >= 2 for i in busy)
+    for (a, b, _), info in zip(decodes, busy):
+        assert any(x < a and b < y for x, y, _ in ticks)
+        (sample,) = inside(samples, a, b)
+        assert sample[2] == info.active
+        assert len(inside(fetches, a, b)) == 1
     admitted = sum(len(i.admitted) for i in infos)
     outside = [s for s in samples + fetches
-               if not any(a < s[0] and s[1] < b for a, b in decodes)]
+               if not any(a < s[0] and s[1] < b for a, b, _ in decodes)]
     assert len(outside) == 2 * admitted
-    for s0, s1 in samples + fetches:
-        assert any(a < s0 and s1 < b for a, b in ticks)
+    assert all(s[2] == 1 for s in outside if s in samples)
+    for s0, s1, _ in samples + fetches:
+        assert any(a < s0 and s1 < b for a, b, _ in ticks)
     # a sample is followed by its fetch, before anything else is sampled
-    order = sorted([(a, "sample") for a, _ in samples]
-                   + [(a, "fetch") for a, _ in fetches])
+    order = sorted([(a, "sample") for a, _, _ in samples]
+                   + [(a, "fetch") for a, _, _ in fetches])
     assert [k for _, k in order] == ["sample", "fetch"] * len(samples)
-    tokens = sum(i.new_tokens for i in infos)
-    assert len(fetches) == tokens
+    assert len(fetches) == len(busy) + admitted
     snap = obs.metrics.snapshot(provenance=PROV)
-    assert snap["counters"]["serve/host_syncs"] == tokens
+    assert snap["counters"]["serve/host_syncs"] == len(busy) + admitted
+    tokens = sum(i.new_tokens for i in infos)
+    assert snap["counters"]["serve/sampled_lanes"] == tokens
